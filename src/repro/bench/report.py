@@ -7,8 +7,9 @@ Report schema (``schema_version`` 1)::
       "scenario": "<name>",
       "description": "...",
       "created_unix": 1234567890,
-      "jax_version": "0.4.37",
+      "jax_version": "0.9.0",
       "backend": "cpu",
+      "device": {"platform": "cpu", "kind": "cpu", "count": 1},
       "spec": { ...ScenarioSpec fields... },
       "engines": {
         "loop": {"wall_s": ..., "compile_s": ..., "rounds_per_sec": ...,
@@ -29,12 +30,14 @@ Report schema (``schema_version`` 1)::
       }
     }
 
-The overlap metrics, ``speedups_vs_loop``, ``model_params``,
-``kernel_check``, ``shard_check``, ``async_check``, ``ttac`` and the
-``telemetry`` block are additive v1 fields (older readers ignore them;
-older reports read back with them absent) — see ``docs/benchmarks.md`` for
-the field-by-field reading guide and ``docs/observability.md`` for the
-telemetry block.  ``model_params`` is the model's total parameter count D
+The ``device`` block, the overlap metrics, ``speedups_vs_loop``,
+``model_params``, ``kernel_check``, ``shard_check``, ``engine_check``,
+``async_check``, ``ttac`` and the ``telemetry`` block are additive v1
+fields (older readers ignore them; older reports read back with them
+absent) — see ``docs/benchmarks.md`` for the field-by-field reading guide
+and ``docs/observability.md`` for the telemetry block.  ``device`` names what
+the run measured: the platform, ``device_kind`` and count of
+``jax.devices()``.  ``model_params`` is the model's total parameter count D
 (the x-axis of the relay D-sweep); ``kernel_check`` records the mandatory
 pallas-vs-reference parity pass (backend, tolerances, measured max |Δ|,
 kernel throughput) for scenarios with ``check_backend`` set.
@@ -42,6 +45,9 @@ kernel throughput) for scenarios with ``check_backend`` set.
 mesh size) is the multi-device gate: sharded engines bitwise-identical to
 each other, allclose to the single-device loop at the recorded tolerance
 (``max_abs_diff`` is the measured divergence — see docs/distributed.md).
+``engine_check`` (runs off the CPU, whose gates hold re-runs at
+``gate_precision`` "highest") holds each timed engine's max |Δ| from the
+timed loop, recorded and not gated.
 ``async_check`` (delayed async scenarios) records the mandatory delay-0
 parity gate — the async engine with the delay stripped is bitwise-identical
 to the loop; ``ttac`` (scenarios with ``ttac_target_loss`` set) is the
@@ -71,6 +77,16 @@ from repro.bench.scenarios import ScenarioSpec
 SCHEMA_VERSION = 1
 
 
+def device_info() -> dict:
+    """The devices a run measured, as JAX reports them."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
 def make_report(spec: ScenarioSpec, result: dict) -> dict:
     """Assemble the JSON payload from a :func:`run_scenario` result."""
     runs: dict[str, EngineRun] = result["runs"]
@@ -84,6 +100,7 @@ def make_report(spec: ScenarioSpec, result: dict) -> dict:
         "created_unix": int(time.time()),
         "jax_version": jax.__version__,
         "backend": jax.default_backend(),
+        "device": device_info(),
         # tuples (e.g. spec.engines) become lists so the payload is exactly
         # what a JSON round trip reads back
         "spec": {
@@ -97,6 +114,7 @@ def make_report(spec: ScenarioSpec, result: dict) -> dict:
         "model_params": result.get("model_params"),
         "kernel_check": result.get("kernel_check"),
         "shard_check": result.get("shard_check"),
+        "engine_check": result.get("engine_check"),
         "async_check": result.get("async_check"),
         "ttac": result.get("ttac"),
         "telemetry": telemetry or None,

@@ -21,6 +21,7 @@ import argparse
 import sys
 
 from repro.bench import harness, report as report_lib, scenarios
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs.summary import format_attribution
 
 
@@ -70,6 +71,16 @@ def format_summary(rep: dict) -> str:
             f"loop (max |Δ| {scheck['max_abs_diff']:.2e} ≤ atol "
             f"{scheck['atol']:g}/rtol {scheck['rtol']:g}), sharded engines "
             "bitwise-identical to each other"
+        )
+    echeck = rep.get("engine_check")
+    if echeck:
+        diffs = "  ".join(
+            f"{name} {diff:.2e}"
+            for name, diff in sorted(echeck["timed_max_abs_diff"].items())
+        )
+        lines.append(
+            f"  gates held at {echeck['gate_precision']} precision; timed "
+            f"runs' max |Δ| vs the timed loop: {diffs}"
         )
     acheck = rep.get("async_check")
     if acheck:
@@ -157,6 +168,7 @@ def main(argv=None) -> int:
             print(format_scenario_line(spec))
         return 0
 
+    use_compile_cache()
     names = args.scenario or ["bench_smoke"]
     engines = tuple(e.strip() for e in args.engines.split(",") if e.strip()) or None
     status = 0

@@ -721,8 +721,9 @@ register(
 # the scan engine on the pallas_fused backend and asserts allclose — so every
 # recorded BENCH_relay_sweep_* report carries both the reference numbers and
 # the kernel parity/throughput (see benchmarks/roofline.py:relay_table).
-# block_d grows with D to keep the interpret-mode grid small on CPU; on TPU
-# the same specs run with interpret off.
+# block_d grows with D to keep the interpret-mode grid small on CPU.  On a
+# TPU the kernels compile, and kernels.ops._block clamps each request to the
+# VMEM tile budget (the 1e7 point's 1,048,576 becomes 65,536 at n=8 in f32).
 
 _RELAY_SWEEP = {
     # name suffix -> (dim, width, rounds, block_d); D = dim·w + w + 10·w + 10

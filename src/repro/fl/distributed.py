@@ -375,7 +375,6 @@ def build_sharded_scan_round_step(
     if exchange not in ("gather", "ring"):
         raise ValueError(f"unknown exchange: {exchange!r} (gather | ring)")
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = sharding_rules.shard_axis(mesh)
@@ -481,12 +480,12 @@ def build_sharded_scan_round_step(
             P() if active is not None else rep(active),
         )
         out_specs = (P(), rep(params), rep(server_state), P())
-        return shard_map(
+        return jax.shard_map(
             _local_scan,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(key, params, server_state, batches, p, lr, A, active)
 
     return scan_rounds

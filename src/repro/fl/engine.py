@@ -650,7 +650,7 @@ class ShardedScanEngine:
             key, params, server_state, batches, p, lr, A=A, active=active
         )
 
-    def _place(self, host):
+    def place(self, host):
         """Staging-side placement: host-stacked chunk → mesh layout.  In
         clients mode, ``device_put`` under ``round_batch_specs`` scatters
         dim 1 over the client axis; in D mode batches are replicated and
@@ -702,6 +702,14 @@ class ShardedScanEngine:
         self.dispatches = 0
         self.prefetch_stats = None
         losses: list = []
+        # the carry goes onto the mesh, replicated, before the first
+        # dispatch: an array's mesh is part of its abstract value, so a
+        # carry that reached the mesh only as the first epoch's output would
+        # retrace (and recompile) the second epoch
+        key, params, server_state = jax.device_put(
+            (key, params, server_state),
+            jax.sharding.NamedSharding(self.mesh, jax.sharding.PartitionSpec()),
+        )
         if self.prefetch == "serial":
             for seg in schedule.segments(rounds):
                 A = jnp.asarray(policy.relay_matrix(seg.state), jnp.float32)
@@ -710,12 +718,12 @@ class ShardedScanEngine:
                         "shard.stage", cat="stage", epoch=seg.epoch_id
                     ):
                         host = [next_batch() for _ in range(seg.n_rounds)]
-                        stacked = self._place(
+                        stacked = self.place(
                             jax.tree.map(lambda *xs: np.stack(xs), *host)
                         )
                 else:
                     host = [next_batch() for _ in range(seg.n_rounds)]
-                    stacked = self._place(
+                    stacked = self.place(
                         jax.tree.map(lambda *xs: np.stack(xs), *host)
                     )
                 key, params, server_state, seg_losses = self._dispatch(
@@ -745,7 +753,7 @@ class ShardedScanEngine:
                 depth=self.prefetch_depth,
                 threaded=self.prefetch == "thread",
                 tracer=self.tracer,
-                place=self._place,
+                place=self.place,
             )
             try:
                 for item in prefetcher:
@@ -787,6 +795,7 @@ def run_rounds_loop(
     lr,
     policy=None,
     on_round: Callable | None = None,
+    before_round: Callable | None = None,
     tracer=None,
 ):
     """The per-round reference driver: the exact loop the figure benchmarks
@@ -795,7 +804,9 @@ def run_rounds_loop(
     dispatch-bound regime the scan engine exists to remove).  Factored out
     so loop-vs-scan comparisons share one definition.  ``tracer`` records
     per-round stage/dispatch/sync spans (the loop already syncs per round,
-    so tracing adds no extra fence here).
+    so tracing adds no extra fence here).  ``before_round(state, sub,
+    params, batch, A)`` sees each round's inputs before it runs, and
+    ``on_round(round, params)`` its result.
     Returns ``(params, server_state, per_round_metrics, key)``."""
     tracer = NULL_TRACER if tracer is None else tracer
     all_metrics = []
@@ -805,6 +816,8 @@ def run_rounds_loop(
         if tracer.enabled:
             with tracer.span("loop.stage", cat="stage", round=state.round):
                 batch = jax.tree.map(jnp.asarray, next_batch())
+            if before_round is not None:
+                before_round(state, sub, params, batch, A)
             with tracer.span("loop.round", cat="dispatch", round=state.round):
                 params, server_state, m = sim.run_round(
                     sub,
@@ -822,6 +835,8 @@ def run_rounds_loop(
                 float(m["loss"])  # the loop driver's per-round host sync
         else:
             batch = jax.tree.map(jnp.asarray, next_batch())
+            if before_round is not None:
+                before_round(state, sub, params, batch, A)
             params, server_state, m = sim.run_round(
                 sub,
                 params,

@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.utils import tree_axpy, tree_scale
 
@@ -53,7 +52,7 @@ from repro.utils import tree_axpy, tree_scale
 def _combined_index(axis_names):
     idx = jax.lax.axis_index(axis_names[0])
     for a in axis_names[1:]:
-        idx = idx * jax.lax.psum(1, a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -107,12 +106,12 @@ def make_ring_round_mixer(A, *, w: float, mesh, client_axes: tuple):
     def mixer(tau, deltas_stacked):
         spec_tau, spec_d = in_specs(deltas_stacked)
         out_spec = jax.tree.map(lambda x: P(*([None] * (x.ndim - 1))), deltas_stacked)
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec_tau, spec_d),
             out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )(jnp.asarray(tau, jnp.float32), deltas_stacked)
 
     return mixer

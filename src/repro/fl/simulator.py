@@ -109,6 +109,19 @@ class FLSimulator:
         (new_params, _), losses = jax.lax.scan(step, (params, opt_state), client_batch)
         return tree_sub(new_params, params), losses[0]
 
+    def local_updates(self, params, batch, lr):
+        """Every client's T local steps from the broadcast ``params``:
+        ``(buf, spec, losses)`` — the raveled (n, D) delta buffer, its
+        :class:`~repro.utils.trees.TreeSpec` and the per-client losses.  The
+        deltas are raveled once, so the aggregation hot spot (and the kernel
+        backends behind it) see one contiguous buffer while the clients ran
+        on the structured view."""
+        deltas, losses = jax.vmap(self._client_update, in_axes=(None, 0, None))(
+            params, batch, lr
+        )
+        buf, spec = stacked_ravel(deltas)
+        return buf, spec, losses
+
     def _round_impl(self, params, server_state, batch, tau, A, lr, active):
         self.trace_count += 1  # python-side: runs only when jit retraces
         return self._round_math(params, server_state, batch, tau, A, lr, active)
@@ -118,13 +131,7 @@ class FLSimulator:
         (``run_round``) and by the epoch-segmented scan engines
         (``repro.fl.engine``), so all paths share one definition and
         stay bit-identical by construction."""
-        deltas, losses = jax.vmap(self._client_update, in_axes=(None, 0, None))(
-            params, batch, lr
-        )
-        # ravel the stacked deltas once: the aggregation hot spot (and the
-        # kernel backends behind it) see one contiguous (n, D) buffer, while
-        # the clients above ran on the structured view
-        buf, spec = stacked_ravel(deltas)
+        buf, spec, losses = self.local_updates(params, batch, lr)
         flat_inc = self.aggregator.flat_fn(tau, buf, A, active)
         increment = tree_unravel(spec, flat_inc, cast=False)
         new_params, new_state = self.server_opt.apply(params, server_state, increment)
@@ -153,6 +160,13 @@ class FLSimulator:
         ``active`` is the churn mask over the padded client dimension (see
         class docstring) — also by value, so membership changes don't retrace.
         """
+        tau, A_round, active_round = self.round_inputs(key, A=A, p=p, active=active)
+        return self._round(params, server_state, batch, tau, A_round, lr, active_round)
+
+    def round_inputs(self, key, *, A=None, p=None, active=None):
+        """``(tau, A, active)`` as ``run_round`` feeds them to the round math:
+        the uplink mask drawn from ``key``, the relay matrix as this
+        simulator's backend takes it, the churn mask as f32."""
         tau = self.sample_tau(key, p)
         A_round = (
             self.A
@@ -160,7 +174,7 @@ class FLSimulator:
             else relay_lib.as_relay_operand(A, n=self.n, backend=self.relay_backend)
         )
         active_round = None if active is None else jnp.asarray(active, jnp.float32)
-        return self._round(params, server_state, batch, tau, A_round, lr, active_round)
+        return tau, A_round, active_round
 
     def sample_tau(self, key, p=None):
         """One round's uplink mask, exactly as ``run_round`` draws it.  The

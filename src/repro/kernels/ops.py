@@ -3,8 +3,8 @@ Pallas kernels.  Leaves are flattened to (n, leaf_size) tiles, streamed
 through the kernel, and restored — so the single-host simulator can run the
 whole D2D consensus as one fused kernel pass per leaf.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU set
-``interpret=False`` (the default flips on TPU backends).
+``interpret=None`` (every wrapper's default) derives the mode from the
+backend: compiled Mosaic kernels on a TPU, the Pallas interpreter elsewhere.
 """
 from __future__ import annotations
 
@@ -98,7 +98,7 @@ def relay_mix(
         out = _k.relay_mix_2d(
             jnp.asarray(A),
             flat,
-            block_d=min(block_d, max(128, flat.shape[1])),
+            block_d=_block(block_d, flat),
             interpret=interpret,
         )
         return out.reshape(leaf.shape)
@@ -132,7 +132,7 @@ def fused_aggregate(
         out = _k.fused_aggregate_2d(
             coeffs,
             flat,
-            block_d=min(block_d, max(128, flat.shape[1])),
+            block_d=_block(block_d, flat),
             interpret=interpret,
         )
         return out.reshape(leaf.shape[1:])
@@ -145,12 +145,28 @@ def fused_aggregate(
 # --------------------------------------------------------------------------
 
 
-def _block(block_d, width: int) -> int:
-    """Clamp the tile width to the buffer (tiny-D scenarios must not pad a
-    64-wide model to a 4096 tile); floor 128 = the TPU lane granule."""
-    return min(
-        _k.DEFAULT_BLOCK_D if block_d is None else block_d, max(128, width)
-    )
+# VMEM the kernels' streamed tiles may take: the (n, block_d) Δ tile and the
+# output tile, each double-buffered by the Pallas pipeline.  Half of the
+# 16 MiB scoped-VMEM default of a TPU v5e core, so the f32 dot result of a
+# tile and the resident (n, n) operand fit beside them.  A tile above it is
+# refused by the chip's compiler (RESOURCE_EXHAUSTED in memory space vmem).
+VMEM_TILE_BUDGET = 8 * 2**20
+
+
+def _block(block_d, buf) -> int:
+    """The Δ tile width for an (n, D) buffer: the requested ``block_d``
+    (None ⇒ kernel default), clamped to the buffer (tiny-D scenarios must
+    not pad a 64-wide model to a 4096 tile) and to ``VMEM_TILE_BUDGET``.
+    Rows count as the buffer dtype's sublane tile pads them (8 for 32-bit,
+    16 for 16-bit).  Floor 128 = the TPU lane granule.  The kernels sum
+    over n only, so the tile width never changes their numbers."""
+    n, width = buf.shape
+    itemsize = jnp.dtype(buf.dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    rows = -(-n // sublanes) * sublanes
+    fit = VMEM_TILE_BUDGET // (4 * rows * itemsize) // 128 * 128
+    want = _k.DEFAULT_BLOCK_D if block_d is None else block_d
+    return max(128, min(want, fit, width))
 
 
 def mix_flat(
@@ -186,7 +202,7 @@ def mix_flat(
     return _k.relay_mix_2d(
         jnp.asarray(A),
         buf,
-        block_d=_block(block_d, buf.shape[1]),
+        block_d=_block(block_d, buf),
         interpret=interpret,
     )
 
@@ -213,6 +229,6 @@ def reduce_flat(
     return _k.fused_aggregate_2d(
         coeffs,
         buf,
-        block_d=_block(block_d, buf.shape[1]),
+        block_d=_block(block_d, buf),
         interpret=interpret,
     )

@@ -35,6 +35,7 @@ from repro import checkpoint
 from repro.channels.delay import make_delays
 from repro.fl.async_engine import AsyncRoundEngine
 from repro.fl.engine import EpochScanEngine, PipelinedScanEngine, run_rounds_loop
+from repro.launch.compile_cache import use_compile_cache
 
 ENGINES = ("loop", "scan", "pipelined", "async")
 
@@ -273,6 +274,7 @@ def main() -> None:  # pragma: no cover - CLI glue over ContinuousTrainer
                     help="restore the newest snapshot in --ckpt-dir")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     n = args.clients
     cfg = creg.get_config(args.arch, reduced=args.reduced)

@@ -2,6 +2,7 @@
 import dataclasses
 import json
 
+import jax
 import pytest
 
 from repro.bench import harness, report as report_lib, scenarios
@@ -84,6 +85,93 @@ def test_harness_runs_all_engines_bitwise_identical():
     assert runs["scan"].overlap_fraction is None
 
 
+@pytest.mark.parametrize(
+    "strategy,backend", [("colrel", "pallas"), ("colrel_fused", "pallas_fused")]
+)
+def test_kernel_check_is_per_round_on_the_sim_path(strategy, backend):
+    spec = dataclasses.replace(
+        TINY, name="tiny_kernel_check", strategy=strategy, check_backend=backend
+    )
+    result = harness.run_scenario(spec, engines=("loop",))
+    check = result["kernel_check"]
+    assert check["mode"] == "per_round" and check["allclose"] is True
+    assert check["backend"] == backend
+    assert check["max_abs_diff"] < 1e-6
+    assert check["first_round_max_abs_diff"] < 1e-6
+    assert check["horizon_max_abs_diff"] >= 0.0
+    assert f"scan_{backend}" in result["runs"]
+
+
+def test_kernel_check_catches_a_kernel_off_by_one_part_in_a_thousand(monkeypatch):
+    """A kernel that is wrong by 0.1% in one round's relay mix raises."""
+    from repro.kernels import relay_mix
+
+    exact = relay_mix.relay_mix_2d
+
+    def skewed(A, delta, **kw):
+        return exact(A, delta, **kw) * 1.001
+
+    monkeypatch.setattr(relay_mix, "relay_mix_2d", skewed)
+    spec = dataclasses.replace(
+        TINY, name="tiny_bad_kernel", strategy="colrel", check_backend="pallas"
+    )
+    with pytest.raises(AssertionError, match="per_round"):
+        harness.run_scenario(spec, engines=("loop",))
+
+
+def _skewed_scan_engine(when):
+    """An EpochScanEngine whose final params are 0.1% off whenever
+    ``when()`` holds — a fault that only the scan program shows."""
+
+    class Skewed(harness.EpochScanEngine):
+        def run_schedule(self, *args, **kwargs):
+            params, *rest = super().run_schedule(*args, **kwargs)
+            if when():
+                params = jax.tree.map(lambda x: x * 1.001, params)
+            return (params, *rest)
+
+    return Skewed
+
+
+def test_kernel_check_gates_the_compiled_scan_program(monkeypatch):
+    """A kernel backend that is right round by round but wrong inside the
+    compiled scan program fails the first-round comparison."""
+    monkeypatch.setattr(harness, "EpochScanEngine", _skewed_scan_engine(lambda: True))
+    spec = dataclasses.replace(
+        TINY, name="tiny_bad_scan", strategy="colrel", check_backend="pallas"
+    )
+    with pytest.raises(AssertionError, match="first_round"):
+        harness.run_scenario(spec, engines=("loop",))
+
+
+def _at_highest():
+    return str(jax.config.jax_default_matmul_precision) == "highest"
+
+
+@pytest.mark.parametrize("fault", ["none", "timed", "gated"])
+def test_pinned_gates_rerun_the_engines_at_highest_precision(monkeypatch, fault):
+    """Off the CPU the gates hold re-runs at "highest" precision bitwise and
+    only record the timed runs' drift: a fault in the timed scan program is
+    recorded, one in the gated program raises."""
+    monkeypatch.setattr(harness, "_pinned", lambda: True)
+    if fault != "none":
+        when = _at_highest if fault == "gated" else lambda: not _at_highest()
+        monkeypatch.setattr(harness, "EpochScanEngine", _skewed_scan_engine(when))
+    spec = dataclasses.replace(TINY, name=f"tiny_pinned_{fault}")
+    if fault == "gated":
+        with pytest.raises(AssertionError, match="scan engine diverged"):
+            harness.run_scenario(spec)
+        return
+    result = harness.run_scenario(spec)
+    check = result["engine_check"]
+    assert result["bitwise_match"] is True
+    assert check["gate_precision"] == "highest"
+    assert set(check["timed_max_abs_diff"]) == {"scan", "pipelined"}
+    assert check["timed_max_abs_diff"]["pipelined"] == 0.0
+    assert (check["timed_max_abs_diff"]["scan"] > 0.0) == (fault == "timed")
+    assert result["runs"]["scan"].trace_count <= 2  # the re-runs are apart
+
+
 TINY_CORR = dataclasses.replace(
     TINY,
     name="tiny_corr_test",
@@ -156,6 +244,13 @@ def test_report_schema_and_roundtrip(tmp_path):
         for k, v in dataclasses.asdict(TINY).items()
     }
     assert rep["spec"]["engines"] == list(TINY.engines)
+    # the devices the run measured, as JAX reports them
+    devices = jax.devices()
+    assert rep["device"] == {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
     assert set(rep["engines"]) == {"loop", "scan"}
     path = report_lib.write_report(rep, tmp_path)
     assert path.name == "BENCH_tiny_test.json"

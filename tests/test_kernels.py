@@ -191,3 +191,24 @@ def test_custom_vjp_gradient_parity_vs_einsum():
     gA_r, gd_r = jax.grad(loss_ref, argnums=(0, 1))(A, d)
     np.testing.assert_allclose(np.asarray(gA_k), np.asarray(gA_r), atol=1e-4)
     np.testing.assert_allclose(np.asarray(gd_k), np.asarray(gd_r), atol=1e-4)
+
+
+@pytest.mark.parametrize("n", [4, 8, 10, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("requested", [None, 4096, 1 << 20])
+def test_block_fits_the_vmem_tile_budget(n, dtype, requested):
+    """The tile width: a lane multiple (or the whole buffer), no wider than
+    the buffer, and its double-buffered in and out tiles — rows padded to
+    the dtype's sublane tile — within ops.VMEM_TILE_BUDGET."""
+    width = 10_013_594
+    buf = jax.ShapeDtypeStruct((n, width), dtype)
+    block = ops._block(requested, buf)
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * (4 // itemsize)
+    rows = -(-n // sublanes) * sublanes
+    assert block % 128 == 0 and 128 <= block <= width
+    assert 4 * rows * block * itemsize <= ops.VMEM_TILE_BUDGET
+    assert block <= (requested or k.DEFAULT_BLOCK_D)
+    # a narrow buffer is one tile, never padded up to the default
+    assert ops._block(requested, jax.ShapeDtypeStruct((n, 200), dtype)) == 200
+    assert ops._block(requested, jax.ShapeDtypeStruct((n, 64), dtype)) == 128

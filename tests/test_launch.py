@@ -198,8 +198,8 @@ def test_snapshot_eval_loop_follows_published_snapshots(tmp_path):
     trainer.init(_params0(), jax.random.key(1))
 
     eval_batch = {"c": np.zeros((N, 2, 4, DIM), np.float32)}
-    loop = SnapshotEvalLoop(
-        d, params_like=_params0(), eval_fn=jax.jit(_loss_fn))
+    eval_fn = jax.jit(_loss_fn)
+    loop = SnapshotEvalLoop(d, params_like=_params0(), eval_fn=eval_fn)
 
     with pytest.raises(RuntimeError, match="poll"):
         loop.eval_batch(eval_batch)
@@ -208,7 +208,9 @@ def test_snapshot_eval_loop_follows_published_snapshots(tmp_path):
     trainer.run(4)
     assert loop.poll() and loop.round == 4
     assert not loop.poll()  # pointer unchanged → no reload
-    direct = float(_loss_fn(trainer.params, eval_batch))
+    # the same compiled eval_fn on the trainer's live params: the reloaded
+    # snapshot must score exactly as the state it was published from
+    direct = float(eval_fn(trainer.params, eval_batch))
     assert loop.eval_batch(eval_batch) == direct
 
     # watch(): train between polls via the injectable sleep

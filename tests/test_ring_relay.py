@@ -53,7 +53,6 @@ def test_block_ring_flat_equals_einsum():
     out = _run("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core import topology, opt_alpha, connectivity, aggregation
 from repro.core import relay as relay_lib
 from repro.fl.ring import ring_colrel_increment_flat
@@ -78,10 +77,10 @@ for k in (4, 8):
             return ring_colrel_increment_flat(
                 A_, t_, b_, w=w_, axis_name="clients", n_shards=k)
 
-        got = jax.jit(shard_map(
+        got = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(None, None), P(None), P(), P("clients", None)),
-            out_specs=P(None), check_rep=False,
+            out_specs=P(None), check_vma=False,
         ))(jnp.asarray(A_eff, jnp.float32), tau_eff, jnp.asarray(w, jnp.float32), buf)
         err = float(jnp.abs(got - want).max())
         assert err < 1e-5, (k, label, err)
