@@ -133,8 +133,9 @@ class EngineRun:
     chunks_staged: int | None = None
     # traced-pass artifacts (``trace_dir`` runs only): the Chrome trace on
     # disk and the per-phase attribution summary.  The traced pass is a
-    # *third* run — its fences serialize the pipeline (observer effect), so
-    # the perf numbers above always come from the untraced warm run.
+    # *third* run — its spans and fences (every engine but the pipelined
+    # one) cost time (observer effect), so the perf numbers above always
+    # come from the untraced warm run.
     trace_path: str | None = None
     telemetry: dict | None = None
     # the warm run's per-round loss trajectory (host floats) — consumed by
@@ -637,8 +638,8 @@ def run_engine(bundle: ScenarioBundle, name: str, batches: list, trace_dir=None)
 
     ``trace_dir`` adds a third, *traced* pass on the already-compiled engine
     and writes ``TRACE_<scenario>_<engine>.json`` (+ ``.jsonl``) there.  The
-    traced pass fences the device per chunk, so its wall time is not the
-    warm measurement — the ``wall_s``/``overlap_fraction`` numbers always
+    traced pass fences the device per dispatch (every engine but the
+    pipelined one), so its wall time is not the warm measurement — the ``wall_s``/``overlap_fraction`` numbers always
     come from the untraced warm run."""
     spec = bundle.spec
     if spec.step == "mesh":

@@ -485,7 +485,9 @@ def _staged_items(
     frame must not pin the prefetcher — see :func:`_worker_loop`).
 
     Telemetry: one ``stage`` span per chunk (batch draws + host stacking)
-    and one ``h2d`` span per chunk (the device transfer), both on the
+    and one ``h2d`` span per chunk (the device transfer), both with the
+    chunk's sequence number in the run as ``chunk`` (the pipelined
+    engine's dispatch span of that chunk carries the same) and both on the
     logical ``prefetcher`` track — in threaded mode that is the worker
     thread's real timeline, in inline mode it is the staging work
     interleaved on the consumer, either way its own Perfetto row.  The
@@ -510,10 +512,15 @@ def _staged_items(
                     track="prefetcher",
                     epoch=seg.epoch_id,
                     rounds=window,
+                    chunk=stats.chunks_staged,
                 ):
                     host = _stack_host([next_batch() for _ in range(window)], pad)
                 with tracer.span(
-                    "prefetch.h2d", cat="h2d", track="prefetcher", epoch=seg.epoch_id
+                    "prefetch.h2d",
+                    cat="h2d",
+                    track="prefetcher",
+                    epoch=seg.epoch_id,
+                    chunk=stats.chunks_staged,
                 ):
                     staged = to_device(host)
             else:

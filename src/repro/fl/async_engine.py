@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.channels.delay import DelayProcess, ZeroDelays
 from repro.core import relay as relay_lib
+from repro.fl.compile_watch import watching_compiles
 from repro.kernels import ops as kernel_ops
 from repro.obs import NULL_TRACER
 from repro.utils.trees import tree_spec, tree_unravel, stacked_ravel
@@ -376,6 +377,7 @@ class AsyncRoundEngine:
 
     # ------------------------------------------------------------- driving
 
+    @watching_compiles
     def run_schedule(self, key, params, server_state, *, schedule, rounds,
                      next_batch, lr, policy=None, on_round=None,
                      reset: bool = True):

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import relay as relay_lib
+from repro.fl.compile_watch import watching_compiles
 from repro.fl.simulator import FLSimulator
 from repro.obs import NULL_TRACER
 
@@ -252,6 +253,7 @@ class EpochScanEngine:
             parts.append(metrics)
         return params, server_state, _concat_metrics(parts)
 
+    @watching_compiles
     def run_schedule(
         self,
         key,
@@ -374,11 +376,13 @@ class PipelinedScanEngine:
         on real accelerators.
 
         ``tracer`` flows to the prefetcher (stage/h2d spans on the
-        ``prefetcher`` track) and adds per-chunk dispatch + device-fence
-        spans on the consumer side.  The fences serialize the pipeline
-        (observer effect): traced runs show *where* time goes, untraced
-        runs measure how fast it is.  Also settable after construction via
-        the ``tracer`` attribute."""
+        ``prefetcher`` track) and adds a dispatch span per chunk on the
+        consumer side; the three spans of one chunk share its ``chunk``
+        attr, the chunk's sequence number in the run.  No fence: the
+        device's own time comes from a device trace, and the pipeline runs
+        as it does untraced.  Compiles inside the run become ``compile``
+        spans (:mod:`repro.fl.compile_watch`).  Also settable after
+        construction via the ``tracer`` attribute."""
         if chunk < 1:
             raise ValueError("chunk must be >= 1")
         if prefetch not in ("inline", "thread"):
@@ -437,6 +441,7 @@ class PipelinedScanEngine:
         )
         return key, params, server_state, metrics
 
+    @watching_compiles
     def run_schedule(
         self,
         key,
@@ -517,6 +522,7 @@ class PipelinedScanEngine:
                         cat="dispatch",
                         epoch=seg.epoch_id,
                         rounds=real,
+                        chunk=self.dispatches,
                     ):
                         key, params, server_state, metrics = self._chunk_fn(
                             key,
@@ -543,18 +549,6 @@ class PipelinedScanEngine:
                     )
                 self.dispatches += 1
                 prefetcher.note_inflight(metrics["loss"])
-                if self.tracer.enabled:
-                    # explicit fence: serializes the pipeline (observer
-                    # effect — traced runs show *where* time goes, not how
-                    # fast the untraced overlap is), but makes blocked-on-
-                    # device time a first-class phase on its own track
-                    with self.tracer.span(
-                        "pipelined.device",
-                        cat="device",
-                        track="device",
-                        epoch=seg.epoch_id,
-                    ):
-                        jax.block_until_ready(metrics["loss"])
                 seg_parts.append((metrics, real))
                 if item.last_in_segment:
                     if on_segment is not None:
@@ -678,6 +672,7 @@ class ShardedScanEngine:
         self.dispatches += 1
         return out
 
+    @watching_compiles
     def run_schedule(
         self,
         key,

@@ -116,10 +116,12 @@ class FLSimulator:
         deltas are raveled once, so the aggregation hot spot (and the kernel
         backends behind it) see one contiguous buffer while the clients ran
         on the structured view."""
-        deltas, losses = jax.vmap(self._client_update, in_axes=(None, 0, None))(
-            params, batch, lr
-        )
-        buf, spec = stacked_ravel(deltas)
+        with jax.named_scope("local_train"):
+            deltas, losses = jax.vmap(self._client_update, in_axes=(None, 0, None))(
+                params, batch, lr
+            )
+        with jax.named_scope("ravel"):
+            buf, spec = stacked_ravel(deltas)
         return buf, spec, losses
 
     def _round_impl(self, params, server_state, batch, tau, A, lr, active):
@@ -130,11 +132,22 @@ class FLSimulator:
         """The round as a pure function — traced both by the per-round jit
         (``run_round``) and by the epoch-segmented scan engines
         (``repro.fl.engine``), so all paths share one definition and
-        stay bit-identical by construction."""
+        stay bit-identical by construction.
+
+        Its device work is named by layer (``jax.named_scope``, metadata
+        only): ``local_train`` (the clients' local steps), ``ravel`` (the
+        delta buffer's ravel and the increment's unravel), ``aggregate``
+        (relay and PS aggregation) and ``server_update``; a device trace
+        finds each op's layer in its name-scope path."""
         buf, spec, losses = self.local_updates(params, batch, lr)
-        flat_inc = self.aggregator.flat_fn(tau, buf, A, active)
-        increment = tree_unravel(spec, flat_inc, cast=False)
-        new_params, new_state = self.server_opt.apply(params, server_state, increment)
+        with jax.named_scope("aggregate"):
+            flat_inc = self.aggregator.flat_fn(tau, buf, A, active)
+        with jax.named_scope("ravel"):
+            increment = tree_unravel(spec, flat_inc, cast=False)
+        with jax.named_scope("server_update"):
+            new_params, new_state = self.server_opt.apply(
+                params, server_state, increment
+            )
 
         # per-client ‖Δ‖² falls out of the buffer for free (one row-sum)
         per_client_dn = jnp.sum(buf * buf, axis=1)

@@ -36,6 +36,7 @@ from repro.channels.delay import make_delays
 from repro.fl.async_engine import AsyncRoundEngine
 from repro.fl.engine import EpochScanEngine, PipelinedScanEngine, run_rounds_loop
 from repro.launch.compile_cache import use_compile_cache
+from repro.obs import NULL_TRACER
 
 ENGINES = ("loop", "scan", "pipelined", "async")
 
@@ -52,6 +53,13 @@ class ContinuousTrainer:
     ``publish_every > 0`` + ``ckpt_dir`` publishes the full training state
     every N rounds (and after the final burst) with atomic latest-pointer
     rotation, keeping the newest ``keep`` snapshots.
+
+    ``tracer`` (a :class:`repro.obs.Tracer`; settable after construction,
+    it follows to the engine) records, besides the engine's spans, where
+    the trainer itself holds the host between bursts: ``trainer.fetch``
+    (category ``fetch``: the burst's metrics to host arrays, which waits
+    for its last dispatch), ``trainer.publish`` and ``trainer.stop``
+    (category ``control``).
     """
 
     def __init__(self, sim, *, schedule, next_batch, lr, policy=None,
@@ -82,11 +90,22 @@ class ContinuousTrainer:
             )
         else:
             self._engine = None
+        self.tracer = tracer
         self._started = False
         self.params = None
         self.server_state = None
         self.key = None
         self.round = 0
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._tracer = NULL_TRACER if tracer is None else tracer
+        if self._engine is not None:
+            self._engine.tracer = self._tracer
 
     # ------------------------------------------------------------ lifecycle
 
@@ -143,18 +162,23 @@ class ContinuousTrainer:
         burst = self.publish_every if self.publish_every > 0 else rounds
         collected: list[dict] = []
         remaining = rounds
+        tracer = self._tracer
         while remaining > 0:
             n = min(burst, remaining)
             metrics = self._run_burst(n)
-            collected.append(
-                {k: np.asarray(v) for k, v in metrics.items()}
-            )
+            # once per burst; on the NULL_TRACER each span is a shared no-op
+            with tracer.span("trainer.fetch", cat="fetch", rounds=n):
+                collected.append({k: np.asarray(v) for k, v in metrics.items()})
             remaining -= n
             self.round += n
             if self.publish_every > 0:
-                self._publish(on_publish)
-            if stop is not None and stop():
-                break
+                with tracer.span("trainer.publish", cat="control"):
+                    self._publish(on_publish)
+            if stop is not None:
+                with tracer.span("trainer.stop", cat="control"):
+                    done = stop()
+                if done:
+                    break
         if self.publish_every == 0 and self.ckpt_dir is not None:
             self._publish(on_publish)
         if not collected:
@@ -170,6 +194,7 @@ class ContinuousTrainer:
                 self.sim, self.key, self.params, self.server_state,
                 schedule=self.schedule, rounds=rounds,
                 next_batch=self.next_batch, lr=self.lr, policy=self.policy,
+                tracer=self._tracer,
             )
         elif self.engine_name == "async":
             out = self._engine.run_schedule(

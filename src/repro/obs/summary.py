@@ -23,7 +23,16 @@ import sys
 from repro.obs.export import load_trace_file, phase_attribution_loaded
 
 # canonical phase order for the table; unknown categories append after
-PHASE_ORDER = ("solve", "stage", "h2d", "dispatch", "device")
+PHASE_ORDER = (
+    "solve",
+    "stage",
+    "h2d",
+    "dispatch",
+    "device",
+    "fetch",
+    "control",
+    "compile",
+)
 
 PHASE_LABEL = {
     "solve": "OPT-α solve",
@@ -31,6 +40,9 @@ PHASE_LABEL = {
     "h2d": "host→device",
     "dispatch": "dispatch",
     "device": "blocked on device",
+    "fetch": "result fetch",
+    "control": "burst control",
+    "compile": "compile",
 }
 
 

@@ -10,11 +10,13 @@ three kinds of facts into a bounded in-memory buffer:
   key=value attrs.  Categories are the attribution axis (the summary CLI
   and the bench ``telemetry`` block group by them); the conventional set is
   ``solve`` / ``stage`` / ``h2d`` / ``dispatch`` / ``device``
-  (blocked-on-device).  Tracks are the *timeline* axis: by default a span
-  lands on its recording thread's track, but a logical override (e.g.
-  ``track="prefetcher"`` for staging work, ``track="device"`` for fence
-  spans) groups related spans onto one named Perfetto row regardless of
-  which thread ran them.
+  (blocked-on-device) / ``fetch`` (device results to the host) /
+  ``control`` (a trainer's bookkeeping between bursts) / ``compile`` (JAX
+  traces and backend compiles, recorded by ``add_span``).  Tracks are the
+  *timeline* axis: by default a span lands on its recording thread's
+  track, but a logical override (e.g. ``track="prefetcher"`` for staging
+  work, ``track="device"`` for fence spans) groups related spans onto one
+  named Perfetto row regardless of which thread ran them.
 * **instants** — ``tracer.instant("segment", cat="schedule", epoch=3)``
   marks a point in time (rendered as a thin arrow in Perfetto); the channel
   schedule uses these for epoch boundaries.
@@ -120,6 +122,9 @@ class NullTracer:
     def instant(self, name, *, cat="default", track=None, **attrs):
         return None
 
+    def add_span(self, name, *, cat="default", dur_ns, track=None, **attrs):
+        return None
+
     def count(self, name, value=1):
         return None
 
@@ -202,6 +207,32 @@ class Tracer:
         attribution phase, ``track`` an optional logical timeline, ``attrs``
         free-form span metadata (must be JSON-serializable for export)."""
         return _Span(self, name, cat, track, attrs)
+
+    def add_span(
+        self,
+        name: str,
+        *,
+        cat: str = "default",
+        dur_ns: int,
+        track: str | None = None,
+        **attrs,
+    ):
+        """Record a span that ends now and lasted ``dur_ns``: work timed
+        elsewhere and reported when it ends (e.g. a compile, by JAX), at
+        the recording thread's current nesting depth."""
+        t1 = self._clock()
+        self._record(
+            SpanEvent(
+                name=name,
+                cat=cat,
+                t0_ns=t1 - int(dur_ns),
+                t1_ns=t1,
+                tid=threading.get_ident(),
+                depth=getattr(self._local, "depth", 0),
+                track=track,
+                attrs=attrs,
+            )
+        )
 
     def instant(self, name: str, *, cat: str = "default", track: str | None = None, **attrs):
         """Mark a point in time (e.g. a segment boundary)."""

@@ -157,6 +157,20 @@ def test_worker_thread_spans_are_thread_safe_and_tracked():
     assert set(tr.thread_names) == tids
 
 
+def test_add_span_ends_now_at_the_current_depth():
+    tr = Tracer(clock=_fake_clock(start=1_000, step=10))  # t_start_ns 1000
+    with tr.span("dispatch", cat="dispatch"):  # enters at 1010
+        tr.add_span("compile.backend", cat="compile", dur_ns=4, fun="f")  # now 1020
+    inner, outer = tr.spans
+    assert (inner.name, inner.cat, inner.t0_ns, inner.t1_ns) == (
+        "compile.backend",
+        "compile",
+        1016,
+        1020,
+    )
+    assert inner.depth == 1 and outer.depth == 0 and inner.attrs == {"fun": "f"}
+
+
 def test_null_tracer_is_inert():
     nt = NullTracer()
     assert nt.enabled is False and NULL_TRACER.enabled is False
@@ -164,6 +178,7 @@ def test_null_tracer_is_inert():
         assert s is not None
     assert nt.instant("x") is None
     assert nt.count("x") is None
+    assert nt.add_span("x", cat="compile", dur_ns=5) is None
     # the disabled span is one shared constant — no per-call allocation
     assert nt.span("a") is nt.span("b") is NULL_TRACER.span("c")
 
@@ -338,11 +353,14 @@ def test_tracing_is_a_pure_observer(engine_name):
     assert np.array_equal(jax.random.key_data(bk), jax.random.key_data(tk))
     # and the traced run actually observed something at every layer
     cats = {s.cat for s in tracer.spans}
-    assert {"solve", "dispatch", "device"} <= cats
+    assert {"solve", "dispatch"} <= cats
     assert tracer.counters["opt_alpha.solves"] > 0
     if engine_name != "loop":
         # the fused engines walk segments(); the loop driver walks rounds()
         assert any(i.name == "segment" for i in tracer.instants)
+    # the loop and scan engines fence each dispatch; the pipelined engine
+    # does not, so that its trace shows the pipeline as it runs untraced
+    assert ("device" in cats) is (engine_name != "pipelined")
     if engine_name == "pipelined":
         assert "stage" in cats and "h2d" in cats
         # one staged chunk per dispatch, folded onto the counters at close
@@ -352,6 +370,132 @@ def test_tracing_is_a_pure_observer(engine_name):
         assert tracer.counters["prefetch.chunks_staged"] == n
         # staging spans land on the logical prefetcher track
         assert {"prefetcher"} <= {s.track for s in tracer.spans if s.track}
+
+
+def test_traced_pipelined_run_has_no_fence_and_joins_chunk_spans(monkeypatch):
+    """The pipelined engine never waits on the device when traced: no
+    ``device`` span, no ``block_until_ready``.  Each chunk's stage, h2d and
+    dispatch spans carry one ``chunk`` id, in that order on the host."""
+    waits = []
+    wait = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: waits.append(1) or wait(x))
+    tracer = Tracer()
+    _run_traced("pipelined", tracer, rounds=14)
+    assert waits == []
+    spans = tracer.spans
+    assert not [s for s in spans if s.cat == "device" or s.name == "pipelined.device"]
+    by_chunk: dict = {}
+    for s in spans:
+        if s.cat in ("stage", "h2d", "dispatch"):
+            by_chunk.setdefault(s.attrs["chunk"], {})[s.cat] = s
+    n = tracer.counters["pipelined.dispatches"]
+    assert sorted(by_chunk) == list(range(n))
+    for parts in by_chunk.values():
+        assert set(parts) == {"stage", "h2d", "dispatch"}
+        assert parts["stage"].t1_ns <= parts["h2d"].t0_ns
+        assert parts["h2d"].t1_ns <= parts["dispatch"].t0_ns
+        assert parts["stage"].attrs["epoch"] == parts["dispatch"].attrs["epoch"]
+
+
+def _run_pipelined(engine, rounds):
+    params = {"x": jax.numpy.ones((4,))}
+    return engine.run_schedule(
+        jax.random.key(0),
+        params,
+        engine.sim.init_server_state(params),
+        schedule=_drift_schedule(),
+        rounds=rounds,
+        next_batch=_batch_stream(6),
+        lr=0.1,
+        policy=channels.AdaptiveOptAlpha(sweeps=10),
+    )
+
+
+def test_compiles_inside_a_traced_run_are_spans_and_counted():
+    """A traced engine's first run records its chunk program's trace and
+    compile; a steady run of the same shapes adds none; a forced retrace
+    adds again.  The listener is gone once no traced run is open."""
+    from repro.fl import compile_watch
+
+    tracer = Tracer()
+    sim = FLSimulator(_quad_loss, n_clients=6, strategy="colrel_fused")
+    engine = PipelinedScanEngine(sim, chunk=4, tracer=tracer)
+
+    def compiles_after_a_run():
+        _run_pipelined(engine, 8)
+        return tracer.counters.get(compile_watch.COUNTER, 0)
+
+    first = compiles_after_a_run()
+    assert first > 0
+    spans = [s for s in tracer.spans if s.cat == "compile"]
+    assert len(spans) == first
+    assert {s.name for s in spans} == {"compile.trace", "compile.backend"}
+    assert any("_chunk_impl" in s.attrs["fun"] for s in spans)
+    assert all(s.dur_ns >= 0 for s in spans)
+    assert compiles_after_a_run() == first  # steady: nothing compiles
+    jax.clear_caches()
+    assert compiles_after_a_run() > first  # forced retrace
+    assert compile_watch._watching == {}
+    # an untraced engine opens no watch
+    _run_pipelined(PipelinedScanEngine(sim, chunk=4), 4)
+    assert compile_watch._watching == {}
+
+
+def test_chunk_program_names_its_layers():
+    """The compiled chunk program's op metadata carries the round's layer
+    scopes, which a device trace reports as each op's name-scope path.  Two
+    leaves, so that the ravel is a real concatenation and not a bitcast."""
+
+    def loss(params, batch):
+        return _quad_loss(params, batch) + jax.numpy.sum(params["y"] ** 2)
+
+    sim = FLSimulator(loss, n_clients=6, strategy="colrel_fused")
+    engine = PipelinedScanEngine(sim, chunk=4)
+    params = {"x": jax.numpy.ones((4,)), "y": jax.numpy.ones((3,))}
+    lowered = engine._chunk_fn.lower(
+        jax.random.key(0),
+        params,
+        sim.init_server_state(params),
+        {"c": np.zeros((4, 6, 2, 4, 4), np.float32)},
+        np.ones(4, bool),
+        jax.numpy.eye(6),
+        jax.numpy.ones(6),
+        0.1,
+        None,
+    )
+    hlo = lowered.compile().as_text()
+    for scope in ("local_train", "ravel", "aggregate", "server_update"):
+        assert f"/{scope}/" in hlo, scope
+
+
+def test_trainer_records_fetch_and_control_spans_per_burst():
+    from repro.launch.train import ContinuousTrainer
+
+    trainer = ContinuousTrainer(
+        FLSimulator(_quad_loss, n_clients=6, strategy="colrel_fused"),
+        schedule=_drift_schedule(),
+        next_batch=_batch_stream(6),
+        lr=0.1,
+        policy=channels.AdaptiveOptAlpha(sweeps=10),
+        engine="pipelined",
+        chunk=4,
+        publish_every=4,
+    )
+    assert trainer.tracer is NULL_TRACER
+    tracer = Tracer()
+    trainer.tracer = tracer  # follows to the engine
+    assert trainer._engine.tracer is tracer
+    trainer.init({"x": jax.numpy.ones((4,))}, jax.random.key(1))
+    stops = []
+    trainer.run(12, stop=lambda: stops.append(1) and False)
+    names = [s.name for s in tracer.spans if s.cat in ("fetch", "control")]
+    assert names == ["trainer.fetch", "trainer.publish", "trainer.stop"] * 3
+    assert len(stops) == 3
+    fetches = [s for s in tracer.spans if s.name == "trainer.fetch"]
+    assert [s.attrs["rounds"] for s in fetches] == [4, 4, 4]
+    # the last burst's fetch follows its dispatches
+    dispatch_end = max(s.t1_ns for s in tracer.spans if s.cat == "dispatch")
+    assert fetches[-1].t0_ns >= dispatch_end
 
 
 def test_null_tracer_default_records_nothing_anywhere():
