@@ -233,9 +233,7 @@ class AsyncRoundEngine:
         bit of ``delta_norm``."""
         self.trace_count += 1  # python side: runs only on retrace
         sim = self.sim
-        deltas, losses = jax.vmap(sim._client_update, in_axes=(None, 0, None))(
-            params, batch, lr
-        )
+        deltas, losses = sim.map_clients(params, batch, lr)
         buf, _ = stacked_ravel(deltas)
         tau_m = jnp.asarray(tau, jnp.float32)
         if sim.strategy == "colrel_fused":

@@ -378,7 +378,9 @@ class PipelinedScanEngine:
         ``tracer`` flows to the prefetcher (stage/h2d spans on the
         ``prefetcher`` track) and adds a dispatch span per chunk on the
         consumer side; the three spans of one chunk share its ``chunk``
-        attr, the chunk's sequence number in the run.  No fence: the
+        attr, the chunk's sequence number in the run, and the dispatch span
+        says how the round maps its clients (``client_map``, see
+        :meth:`FLSimulator.map_clients`).  No fence: the
         device's own time comes from a device trace, and the pipeline runs
         as it does untraced.  Compiles inside the run become ``compile``
         spans (:mod:`repro.fl.compile_watch`).  Also settable after
@@ -523,7 +525,7 @@ class PipelinedScanEngine:
                         epoch=seg.epoch_id,
                         rounds=real,
                         chunk=self.dispatches,
-                    ):
+                    ) as span:
                         key, params, server_state, metrics = self._chunk_fn(
                             key,
                             params,
@@ -535,6 +537,8 @@ class PipelinedScanEngine:
                             lr,
                             active_seg,
                         )
+                        # known once the first dispatch has traced the round
+                        span.set(client_map=self.sim.client_map)
                 else:
                     key, params, server_state, metrics = self._chunk_fn(
                         key,

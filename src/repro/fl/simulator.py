@@ -1,7 +1,7 @@
 """Single-host FL simulator (paper-scale: n≈10 clients, small models).
 
 Implements Algs. 1 + 2 literally: per round —
-  broadcast x^(r) → T local SGD steps per client (vmap over clients) →
+  broadcast x^(r) → T local SGD steps per client (map over clients) →
   D2D relay Δx̃ = A·Δx → Bernoulli τ mask → blind PS aggregation → server opt.
 
 Used by the paper-figure benchmarks (Figs. 2-4), the convergence tests and
@@ -14,12 +14,36 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import aggregation
 from repro.core import relay as relay_lib
 from repro.core.aggregation import ServerOpt
 from repro.optim.sgd import ClientOpt
 from repro.utils import stacked_ravel, tree_sub, tree_unravel
+
+
+def _abstract(x, skip=0):
+    """``x``'s shape (less its ``skip`` leading axes) and dtype, no value."""
+    return jax.ShapeDtypeStruct(jnp.shape(x)[skip:], jnp.result_type(x))
+
+
+def _has_conv(jaxpr) -> bool:
+    """Whether ``jaxpr`` (a ``Jaxpr`` or ``ClosedJaxpr``) runs a convolution,
+    looking into every sub-jaxpr an equation carries (``pjit``,
+    ``custom_jvp_call``, ``scan``, ``cond``'s branches, ...)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            return True
+        for value in eqn.params.values():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            if any(
+                isinstance(sub, (Jaxpr, ClosedJaxpr)) and _has_conv(sub)
+                for sub in subs
+            ):
+                return True
+    return False
 
 
 def _metrics(loss, tau, delta_norm):
@@ -94,6 +118,9 @@ class FLSimulator:
             interpret=interpret,
         )
         self.trace_count = 0
+        # how map_clients maps _client_update over the clients ("sequential"
+        # or "vmap"); decided from the loss at its first trace
+        self.client_map: str | None = None
         self._round = jax.jit(self._round_impl)
 
     # -- one client: T local SGD steps from the broadcast global model -----
@@ -109,17 +136,37 @@ class FLSimulator:
         (new_params, _), losses = jax.lax.scan(step, (params, opt_state), client_batch)
         return tree_sub(new_params, params), losses[0]
 
-    def local_updates(self, params, batch, lr):
-        """Every client's T local steps from the broadcast ``params``:
-        ``(buf, spec, losses)`` — the raveled (n, D) delta buffer, its
-        :class:`~repro.utils.trees.TreeSpec` and the per-client losses.  The
-        deltas are raveled once, so the aggregation hot spot (and the kernel
-        backends behind it) see one contiguous buffer while the clients ran
-        on the structured view."""
-        with jax.named_scope("local_train"):
-            deltas, losses = jax.vmap(self._client_update, in_axes=(None, 0, None))(
-                params, batch, lr
+    def map_clients(self, params, batch, lr):
+        """``_client_update`` over every client from the broadcast
+        ``params``: ``(deltas, losses)``, leaves stacked ``(n, ...)``.
+
+        A loss that runs a convolution maps the clients one after another
+        (``lax.map``): under ``vmap`` each client's own weights turn every
+        convolution into an n-way grouped one, with layout transposes
+        around it and its weight gradient off the MXU.  Every other loss
+        keeps ``vmap``, where per-client weights become a batched
+        ``dot_general``.  The choice is made once, from the loss traced on
+        one client's minibatch, and kept in ``client_map``."""
+        if self.client_map is None:
+            # abstract shapes only: leaves of ``batch`` are (n, T, b, ...)
+            jaxpr = jax.make_jaxpr(self.loss_fn)(
+                jax.tree.map(_abstract, params),
+                jax.tree.map(lambda x: _abstract(x, skip=2), batch),
             )
+            self.client_map = "sequential" if _has_conv(jaxpr) else "vmap"
+        if self.client_map == "sequential":
+            return jax.lax.map(lambda b: self._client_update(params, b, lr), batch)
+        return jax.vmap(self._client_update, in_axes=(None, 0, None))(params, batch, lr)
+
+    def local_updates(self, params, batch, lr):
+        """Every client's T local steps from the broadcast ``params``
+        (:meth:`map_clients`): ``(buf, spec, losses)`` — the raveled (n, D)
+        delta buffer, its :class:`~repro.utils.trees.TreeSpec` and the
+        per-client losses.  The deltas are raveled once, so the aggregation
+        hot spot (and the kernel backends behind it) see one contiguous
+        buffer while the clients ran on the structured view."""
+        with jax.named_scope("local_train"):
+            deltas, losses = self.map_clients(params, batch, lr)
         with jax.named_scope("ravel"):
             buf, spec = stacked_ravel(deltas)
         return buf, spec, losses

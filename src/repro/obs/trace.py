@@ -146,6 +146,11 @@ class _Span:
         self._track = track
         self._attrs = attrs
 
+    def set(self, **attrs):
+        """Add attrs known only once the span's work has run (recorded on
+        exit with the rest)."""
+        self._attrs.update(attrs)
+
     def __enter__(self):
         local = self._tracer._local
         self._depth = getattr(local, "depth", 0)

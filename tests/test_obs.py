@@ -411,6 +411,29 @@ def _run_pipelined(engine, rounds):
     )
 
 
+def _conv_loss(params, batch):
+    y = jax.lax.conv_general_dilated(
+        batch["c"][:, None, :], params["x"][None, None, :], (1,), "SAME"
+    )
+    return _quad_loss(params, batch) + 0.5 * jax.numpy.mean(y**2)
+
+
+@pytest.mark.parametrize(
+    "loss, client_map", [(_quad_loss, "vmap"), (_conv_loss, "sequential")]
+)
+def test_chunk_dispatch_span_says_how_clients_are_mapped(loss, client_map):
+    """Each traced chunk dispatch carries the client map its round was
+    traced with: sequential for a convolutional loss, vmap otherwise."""
+    tracer = Tracer()
+    sim = FLSimulator(loss, n_clients=6, strategy="colrel_fused")
+    _run_pipelined(PipelinedScanEngine(sim, chunk=4, tracer=tracer), 8)
+    dispatches = [s for s in tracer.spans if s.name == "pipelined.chunk"]
+    n = tracer.counters["pipelined.dispatches"]
+    assert len(dispatches) == n > 1
+    assert [s.attrs["client_map"] for s in dispatches] == [client_map] * n
+    assert sim.client_map == client_map
+
+
 def test_compiles_inside_a_traced_run_are_spans_and_counted():
     """A traced engine's first run records its chunk program's trace and
     compile; a steady run of the same shapes adds none; a forced retrace
