@@ -18,6 +18,47 @@ freed, and the plain reference (``reference.py``) replays the first three
 rounds from the same seed.  Every OPT-alpha solve the trainer used is held
 to its problem (``opt_alpha_ref.py``), and every round's uplink mask, in
 set-up and window, to the reference's draw from the round key chain.
+
+The replay runs where ``reference.replay_device`` puts it:
+
+* on the host's CPU where the model's loss runs a convolution.
+  ResNet-20 replays there: the TPU compiler takes about 19 minutes over
+  its ``HIGHEST`` convolutions, and the CPU replays its three rounds in
+  seconds;
+* on the cell's first device otherwise, for a model of matmuls whose
+  replay at published widths would not fit the run budget on the host.
+  It holds about ``5 * 4 * D`` bytes (float32) plus one local step's
+  activations (``reference.py``).  The peak memory is read before the
+  check, so the replay never enters ``peak_hbm_gib``; the chip's bytes in
+  use before the check are printed on standard error, to show that the
+  trainer's state was freed.
+
+Every run prints, on standard error before its ``check`` lines, the host
+seconds of its phases, which sum to the seconds from the start of
+``run.py`` to that line (``wall``):
+
+    phases: weights=... checked=... warm=... window=... trace_stop=... read=... check=... wall=...
+
+``weights``: imports, TPU init, the bundle, data and the weights on the
+device; ``checked``: the checked rounds, which hold the chunk program's
+compile or its load from the cache; ``warm``: the rounds up to the chunk
+boundary and the warm burst; ``window``: the measured window, less
+``trace_stop``: the profiler's stop, which collects and writes the
+device trace (0 in an untraced run); ``read``: the peak memory, the
+trace's reduction and the per-layer metrics, and freeing the trainer;
+``check``: the reference's init, compile and replay, the comparison and
+the OPT-alpha numbers.  A traced run also prints how many device op
+events its trace holds.
+
+The run budget: a run has 360 s to end, and a run still going then is
+stopped.  Size a new cell so that its slowest run, traced and with a
+cold compile cache, ends within 300 s, and its untraced runs within
+180 s.  A traced run drives the chip for at least the set-up's rounds
+(``CHECKED_ROUNDS``, the rounds up to the chunk boundary, one burst) and
+then ``max(seconds, 1 + TRACED_BURSTS bursts)``: the profiler starts
+after the window's first burst and stops ``TRACED_BURSTS`` bursts later.
+The stop writes every device op event it recorded, and the reduction
+reads each.
 """
 from __future__ import annotations
 
@@ -136,6 +177,9 @@ class Prepared:
     # on the host: "p0", "after", "losses", "delta_norms" of the checked
     # rounds; "taus" and "all_losses" of every round the trainer ran
     program: dict
+    # host seconds (perf_counter) when the weights were on the device and
+    # when the checked rounds had run
+    marks: dict
 
 
 def build_spec(cell, seed: int):
@@ -186,6 +230,7 @@ def prepare(cell, seed: int, *, traced: bool = False) -> Prepared:
     trainer.init(params, jax.random.key(s + 1))
     program = {"p0": jax.device_get(params), "after": [], "taus": [], "all_losses": []}
     del params
+    marks = {"weights": time.perf_counter()}
     delta_norms = []
     for _ in range(CHECKED_ROUNDS):
         m = trainer.run(1)
@@ -196,6 +241,7 @@ def prepare(cell, seed: int, *, traced: bool = False) -> Prepared:
         )
     program["losses"] = np.asarray(program["all_losses"])
     program["delta_norms"] = np.asarray(delta_norms)
+    marks["checked"] = time.perf_counter()
     realign = (-CHECKED_ROUNDS) % spec.chunk
     if realign:
         record(program, trainer.run(realign))
@@ -204,16 +250,16 @@ def prepare(cell, seed: int, *, traced: bool = False) -> Prepared:
     record(program, trainer.run(burst))
     if traced:
         trace_reduce.mark()
-    return Prepared(trainer, spec, s, feed, policy, schedule, tracer, program)
+    return Prepared(trainer, spec, s, feed, policy, schedule, tracer, program, marks)
 
 
 def check(cell, prep: Prepared, *, control: bool = False) -> dict:
-    """Replay the checked rounds through the plain float32 reference (on
-    the host's CPU) and compare what the trainer produced with it:
-    ``{"program": numbers}``.  Every OPT-alpha solve the trainer used is
-    held to its problem, and every round's uplink mask to the reference's
-    draw.  With ``control`` also the reference in bfloat16 on the chip, in
-    the program's place: ``"control_bf16"``."""
+    """Replay the checked rounds through the plain float32 reference, on
+    the device ``reference.replay_device`` picks, and compare what the trainer produced with it: ``{"program": numbers}``.
+    Every OPT-alpha solve the trainer used is held to its problem, and
+    every round's uplink mask to the reference's draw.  With ``control``
+    also the reference in bfloat16 on the chip, in the program's place:
+    ``"control_bf16"``."""
     import jax
     import jax.numpy as jnp
 
@@ -223,8 +269,13 @@ def check(cell, prep: Prepared, *, control: bool = False) -> dict:
     program = prep.program
     channel = prep.schedule.channel[: len(program["taus"])]
     cpu = jax.devices("cpu")[0]
+    chip = jax.devices()[0]
+    where = reference.replay_device(model, cfg, prep.feed.kept[0])
+    in_use = (chip.memory_stats() or {}).get("bytes_in_use", "not reported")
+    print(f"chip bytes in use before the check: {in_use}", file=sys.stderr)
     with jax.default_device(cpu):
         taus = reference.draw_taus(jax.random.key(s + 1), [c["p"] for c in channel])
+    with jax.default_device(where):
         p0 = jax.device_get(jax.jit(functools.partial(model.init, cfg=cfg))(jax.random.key(s)))
     # each checked round ran as a burst of its own: one solve each
     rounds = [
@@ -235,7 +286,7 @@ def check(cell, prep: Prepared, *, control: bool = False) -> dict:
         reference.run_rounds, model, p0, rounds,
         lr=prep.spec.lr, wd=cfg["client_weight_decay"],
     )
-    ref = dict(run(device=cpu), p0=p0)
+    ref = dict(run(device=where), p0=p0)
     numbers = reference.compare(program, ref)
     numbers.update(opt_alpha_ref.solve_numbers(prep.policy.solves))
     # every round's uplink mask against the reference's draw
@@ -279,7 +330,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, t_start: fl
     prep = prepare(cell, seed, traced=trace)
     trainer = prep.trainer
     ends: list = []  # (host seconds, round, staging wait) after each burst
-    tracing = {"dir": None, "done": not trace, "from": None}
+    tracing = {"dir": None, "done": not trace, "from": None, "stop_s": 0.0}
 
     def stop():
         now = time.perf_counter()
@@ -293,13 +344,13 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, t_start: fl
     t_window = time.perf_counter()
     setup_s = t_window - t_start
     metrics = trainer.run(10**15, stop=stop)
+    t_read = time.perf_counter()
     compiles["on"] = False
     t_last, round_last, _ = ends[-1]
     rounds = round_last - round0
     failed = int(np.sum(~np.isfinite(np.asarray(metrics["loss"]))))
     record(prep.program, metrics)
-    stats = devices[0].memory_stats() or {}
-    peak_bytes = int(stats.get("peak_bytes_in_use", 0))
+    peak_bytes = peak_memory(devices[0])
 
     result_metrics = {}
     summary = None
@@ -320,6 +371,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, t_start: fl
     del trainer, metrics
     prep.trainer = None
     gc.collect()
+    t_check = time.perf_counter()
     numbers = check(cell, prep)["program"]
     for name, value in numbers.items():
         print(f"reading {name}: {value!r}", file=sys.stderr)
@@ -329,6 +381,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, t_start: fl
         failed == 0
         and all(math.isfinite(c["value"]) and c["value"] <= c["limit"] for c in checks.values())
     )
+    t_end = time.perf_counter()
     result = {
         "correct": correct,
         "attempted": int(rounds),
@@ -349,7 +402,24 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, t_start: fl
     print(f"traces and compiles in the window: {compiles['n']}", file=sys.stderr)
     bursts = np.diff([t_window] + [t for t, _, _ in ends])
     print(f"burst seconds: {' '.join(f'{b:.3f}' for b in bursts)}", file=sys.stderr)
+    bounds = {
+        "weights": (t_start, prep.marks["weights"]),
+        "checked": (prep.marks["weights"], prep.marks["checked"]),
+        "warm": (prep.marks["checked"], t_window),
+        "window": (t_window, t_read - tracing["stop_s"]),
+        "trace_stop": (0.0, tracing["stop_s"]),
+        "read": (t_read, t_check),
+        "check": (t_check, t_end),
+        "wall": (t_start, t_end),
+    }
+    print("phases: " + " ".join(f"{k}={b - a:.3f}" for k, (a, b) in bounds.items()),
+          file=sys.stderr)
     return result
+
+
+def peak_memory(device) -> int:
+    """The device's peak bytes in use so far (0 where it reports none)."""
+    return int((device.memory_stats() or {}).get("peak_bytes_in_use", 0))
 
 
 def _count_compiles() -> dict:
@@ -389,7 +459,9 @@ def _advance_trace(tracing: dict, bursts: int, round_now: int) -> None:
         tracing["from"] = round_now
     trace_reduce.mark()
     if bursts == 1 + TRACED_BURSTS:
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        tracing["stop_s"] = time.perf_counter() - t0
         tracing["to"] = round_now
         tracing["done"] = True
 
@@ -399,7 +471,11 @@ def _read_trace(trace_dir):
         paths = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
         if not paths:
             return None
-        return trace_reduce.reduce(trace_reduce.from_xplane(paths[-1]))
+        trace = trace_reduce.from_xplane(paths[-1])
+        events = sum(len(v) for v in trace["ops"].values())
+        print(f"trace: {events} device op events, {paths[-1].stat().st_size} bytes",
+              file=sys.stderr)
+        return trace_reduce.reduce(trace)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 

@@ -17,10 +17,29 @@ The uplink mask tau of each round is drawn here from the round key chain
 documents.  The relay weights A, the uplink marginals p, the cohort mask
 and the batches are the round's inputs as the host layer fed them.
 
-``dtype=float32`` runs at ``HIGHEST`` matmul precision; the benchmark
-runs it on the host's CPU, where float32 is exact and the TPU compiler's
-long compile of ``HIGHEST`` convolutions is avoided.  The control runs
-the same code in bfloat16, on the chip.
+``dtype=float32`` runs at ``HIGHEST`` matmul precision, on the device
+that ``replay_device`` picks from the model's loss:
+
+* the host's CPU where the loss runs a convolution, where float32 is
+  exact.  ResNet-20 replays there: the TPU compiler takes about 19
+  minutes over its ``HIGHEST`` convolutions, and the CPU replays its
+  three rounds in seconds;
+* JAX's first device otherwise, the cell's first chip once the trainer's
+  state is freed: a model of matmuls at published widths (a transformer)
+  needs minutes of the host's CPU for its replay, more than a run has.
+
+The control runs the same code in bfloat16, on the chip.
+
+Memory of the replay, D parameters of ``b`` bytes each: the parameters,
+the round's increment (``_axpy`` donates its accumulator, the round's
+update donates the old parameters) and one client's local step inside
+``_client`` (its start, its running parameters, their gradient and the
+update it returns), about ``5 * b * D`` bytes on the device plus one
+step's activations.  ``model.loss`` is traced inside that one jitted
+step, so a model module may compute its loss in blocks of rows
+(``lax.map``, ``lax.scan``, ``jax.checkpoint``) to bound the
+activations.  The host keeps float32 copies of the start and of each
+round's parameters, ``4 * D`` bytes each.
 """
 from __future__ import annotations
 
@@ -29,6 +48,33 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
+
+
+def has_conv(jaxpr) -> bool:
+    """Whether ``jaxpr`` (a ``Jaxpr`` or ``ClosedJaxpr``) runs a convolution,
+    looking into every sub-jaxpr an equation carries (``pjit``,
+    ``custom_jvp_call``, ``scan``, ``cond``'s branches, ...)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            return True
+        for value in eqn.params.values():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            if any(isinstance(sub, (Jaxpr, ClosedJaxpr)) and has_conv(sub) for sub in subs):
+                return True
+    return False
+
+
+def replay_device(model, cfg, batch):
+    """Where the float32 replay runs: the host's CPU where ``model.loss``
+    runs a convolution on one minibatch of ``batch`` (leaves ``(n, T, b,
+    ...)``), else JAX's first device.  Traced on shapes alone."""
+    params = jax.eval_shape(functools.partial(model.init, cfg=cfg), jax.random.key(0))
+    mb = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[2:], x.dtype), batch)
+    conv = has_conv(jax.make_jaxpr(model.loss)(params, mb))
+    return jax.devices("cpu")[0] if conv else jax.devices()[0]
+
 
 def round_coefficients(A, tau, active):
     """Per-origin weights of the PS increment, in float64 on the host."""
@@ -68,9 +114,14 @@ def _client(params, batches, lr, wd, *, loss_fn, steps):
     return delta, first.astype(jnp.float32)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _axpy(acc, c, delta):
     return jax.tree.map(lambda s, d: s + c.astype(s.dtype) * d, acc, delta)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _apply(params, inc):
+    return jax.tree.map(lambda w, d: w + d.astype(w.dtype), params, inc)
 
 
 def run_rounds(model, params, rounds, *, lr, wd, dtype=jnp.float32, device=None):
@@ -117,7 +168,7 @@ def _run(model, params, rounds, lr, wd, dtype, precision):
                     inc = _axpy(inc, jnp.asarray(c[i], jnp.float32), delta)
                 loss_sum += a[i] * float(first)
                 sq_sum += a[i] * float(_sq_norm(delta))
-            p = jax.tree.map(lambda w, d: w + d.astype(w.dtype), p, inc)
+            p = _apply(p, inc)
             after.append(jax.tree.map(lambda x: np.asarray(x, np.float32), p))
             losses.append(loss_sum / max(a.sum(), 1.0))
             delta_norms.append(np.sqrt(sq_sum / max(a.sum(), 1.0)))

@@ -46,6 +46,18 @@ def test_reference_agrees_with_the_simulator_and_a_planted_fault_fails(prepared)
     assert harness.check(cell, prep_f)["program"]["step1"] > 5e-4
 
 
+def test_mlp_reference_agrees_with_the_simulator(tiny):
+    # the plain MLP whose replay runs on the chip: exact float32 on the CPU
+    cell = cells.resolve(tiny, "tiny_mlp.tiny_fig5")
+    prep = harness.prepare(cell, 2**31 + 7)
+    prep.trainer = None
+    numbers = harness.check(cell, prep)["program"]
+    assert numbers["p0_gap"] == 0.0 and numbers["tau_mismatch"] == 0
+    assert max(numbers["loss"], numbers["step1"], numbers["change3"]) < 1e-4, numbers
+    limits = cell.limits["limits"]
+    assert all(numbers[k] <= limits[k] for k in limits), (numbers, limits)
+
+
 def test_the_control_fails_the_cell_limits(prepared):
     cell, prep = prepared
     out = harness.check(cell, prep, control=True)
